@@ -5,9 +5,10 @@ sharded, plus deadline-flush serving tail latency.
 Tracks the perf trajectory of the lut_gather serving path across PRs.
 Six execution strategies over identical synthesised networks:
 
-  seed        per-layer pallas_call, int32 tables, broadcast gather —
-              the layout/blocking the repo shipped with at seed
-  per-layer   per-layer pallas_call, packed uint8 tables, flat gather
+  seed        the seed's broadcast-gather layout over int32 tables, as
+              the plain jnp oracle chain (kernels/lut_gather/ref.py);
+              the Pallas kernels no longer carry that layout
+  per-layer   per-layer pallas_call, packed uint8 tables, lane-gather
   fused       whole network in ONE pallas_call, packed uint8 tables,
               matmul routing, VMEM activation scratch
   fused-int4  the fused engine on int4 NIBBLE-PACKED slabs — two codes
@@ -163,6 +164,13 @@ CONFIGS = [
 ]
 
 
+def _ref_chain(tables, codes):
+    for t in tables:
+        codes = lg_ref.lut_layer(codes, t.conn, t.sub_table, t.add_table,
+                                 t.in_bits, t.sub_bits)
+    return codes
+
+
 def _bench_config(name: str, kw: dict, batch: int, iters: int):
     spec = LD.ModelSpec(name=name, **kw)
     model = LD.init_model(jax.random.key(0), spec)
@@ -174,12 +182,8 @@ def _bench_config(name: str, kw: dict, batch: int, iters: int):
         2 ** spec.layer_specs()[0].in_quant.bits).astype(jnp.int32)
 
     # bit-exactness guard: a benchmark of a wrong kernel is worthless
-    want = codes
-    for t in legacy:
-        want = lg_ref.lut_layer(want, t.conn, t.sub_table, t.add_table,
-                                t.in_bits, t.sub_bits)
-    seed_fn = jax.jit(
-        lambda c: lg_ops.lut_network(legacy, c, broadcast_tables=True))
+    want = _ref_chain(legacy, codes)
+    seed_fn = jax.jit(lambda c: _ref_chain(legacy, c))
     per_layer_fn = jax.jit(lambda c: lg_ops.lut_network(packed, c))
     per_layer_i32_fn = jax.jit(lambda c: lg_ops.lut_network(legacy, c))
     fused_fn = lg_ops.make_network_fn(packed, fused=True, block_b=batch)
@@ -1080,4 +1084,6 @@ if __name__ == "__main__":
     ap.add_argument("--json", action="store_true",
                     help="write BENCH_lut_infer.json at the repo root")
     a = ap.parse_args()
+    from repro.xla_env import use_compile_cache
+    use_compile_cache()
     run(fast=a.fast, write_json=a.json)

@@ -17,7 +17,7 @@ import traceback
 
 # virtual host devices for the sharded lut_infer series — must be set
 # before ANY benchmark module initialises jax
-from repro.xla_env import ensure_host_devices
+from repro.xla_env import ensure_host_devices, use_compile_cache
 
 ensure_host_devices(4)
 
@@ -46,6 +46,7 @@ def main() -> None:
                     help="write BENCH_lut_infer.json at the repo root "
                          "(stable schema, tracked across PRs)")
     args = ap.parse_args()
+    use_compile_cache()
 
     only = set(args.only.split(",")) if args.only else None
     summary = []

@@ -98,6 +98,7 @@ import tempfile
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -222,8 +223,11 @@ def main():
         print(f"aggregate throughput "
               f"{sum(len(h) for h in handles.values()) / span:,.0f} req/s "
               f"across {len(served_ids)} concurrent models")
-    print("(CPU interpret-mode numbers; TPU deploys the same kernels "
-          "with VMEM-resident tables — see kernels/lut_gather)")
+    dev = jax.devices()[0]
+    mode = ("compiled Pallas kernels" if dev.platform == "tpu"
+            else "Pallas interpreter")
+    print(f"(measured on {dev.device_kind} ({dev.platform}) "
+          f"x{jax.device_count()}, {mode})")
 
 
 if __name__ == "__main__":

@@ -50,19 +50,21 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.lut_gather.lut_gather import (MATMUL_ROUTE_MAX_BITS,
+                                                 batch_tile,
                                                  dummy_add_table,
                                                  lut_gather_pallas,
                                                  lut_network_fused_pallas,
-                                                 routing_matrix)
+                                                 routing_matrix, slot_rows,
+                                                 stage_rows)
 from repro.kernels.lut_gather import ref
 
 # VMEM a fused network may claim for tables + activation scratch before
-# we refuse to fuse (per-core budget is ~16 MB; leave headroom for the
-# batch tile, padding, and the compiler)
+# we refuse to fuse.  Each pallas_call asks the compiler for twice its
+# own claim (at least 32 MiB, lut_gather._compiler_params), which
+# leaves room for double-buffered tiles, lane padding and temporaries
 FUSED_VMEM_BUDGET_BYTES = 12 * 2 ** 20
 
 
@@ -72,26 +74,33 @@ def _backend() -> str:
 
 
 def _default_interpret(force_interpret: Optional[bool]) -> bool:
-    return (_backend() != "tpu") if force_interpret is None else force_interpret
+    """Compiled kernels on a TPU, the Pallas interpreter on the CPU
+    (tests, CPU-only hosts); any other backend is an error rather than
+    a silent interpreter run."""
+    if force_interpret is not None:
+        return force_interpret
+    backend = _backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"LUT kernels compile for TPU only (backend {backend!r}); "
+            "pass force_interpret=True to run the Pallas interpreter")
+    return backend == "cpu"
 
 
 def lut_layer(codes: jnp.ndarray, conn: jnp.ndarray,
               sub_table: jnp.ndarray, add_table: jnp.ndarray,
               in_bits: int, sub_bits: int,
               force_interpret: Optional[bool] = None,
-              broadcast_tables: bool = False,
               sub_packed: bool = False,
               add_packed: bool = False) -> jnp.ndarray:
     return lut_gather_pallas(codes, conn, sub_table, add_table,
                              in_bits=in_bits, sub_bits=sub_bits,
                              interpret=_default_interpret(force_interpret),
-                             broadcast_tables=broadcast_tables,
                              sub_packed=sub_packed, add_packed=add_packed)
 
 
 def lut_network(tables: List, codes: jnp.ndarray,
-                force_interpret: Optional[bool] = None,
-                broadcast_tables: bool = False) -> jnp.ndarray:
+                force_interpret: Optional[bool] = None) -> jnp.ndarray:
     """Per-layer path: one pallas_call per layer, codes round-trip
     through HBM between layers.  tables: List[LayerTables]; int4
     nibble-packed slabs run through the in-kernel unpack."""
@@ -99,7 +108,6 @@ def lut_network(tables: List, codes: jnp.ndarray,
         codes = lut_layer(codes, t.conn, t.sub_table, t.add_table,
                           t.in_bits, t.sub_bits,
                           force_interpret=force_interpret,
-                          broadcast_tables=broadcast_tables,
                           sub_packed=getattr(t, "sub_packed", False),
                           add_packed=getattr(t, "add_packed", False))
     return codes
@@ -160,14 +168,15 @@ def _flatten_network(tables: List, n_in0: int):
 
 def _tile_bytes(n_in0: int, widths: List[int], block_b: int,
                 pipeline: bool) -> int:
-    """int32 batch-tile + activation-scratch bytes of the fused kernel.
-    Grid mode holds one (TB, n_in) in block and one (TB, n_out_last)
-    out block; the double-buffered pipeline holds TWO of each (its DMA
-    slots).  Both stage activations through one (TB, max_width)
-    scratch."""
-    max_w = max([n_in0] + widths)
-    n_buf = 2 if pipeline else 1
-    return block_b * 4 * (n_buf * (n_in0 + widths[-1]) + max_w)
+    """int32 batch-tile + activation-scratch bytes of the fused kernel
+    at its batch tile TB (``block_b`` in whole 128-lane groups).  Grid
+    mode holds one (n_in, TB) in block and one (n_out_last, TB) out
+    block; the double-buffered pipeline holds TWO of each (its DMA
+    slots, in whole 8-row sublane tiles).  Both stage hidden
+    activations through one (stage_rows, TB) scratch."""
+    io = (2 * (slot_rows(n_in0) + slot_rows(widths[-1])) if pipeline
+          else n_in0 + widths[-1])
+    return batch_tile(block_b) * 4 * (io + stage_rows(widths))
 
 
 def fused_vmem_bytes(tables: List, block_b: int = 1024,
@@ -219,16 +228,17 @@ def fused_vmem_actual(tables: List, block_b: int = 1024,
     slab = sum(int(a.size) * a.dtype.itemsize for a in flat)
     # mirror of lut_network_fused_pallas's in/out specs + scratch_shapes
     n_out_last = metas[-1][4]
-    max_width = max([n_in] + [m[4] for m in metas])
+    rows = stage_rows([m[4] for m in metas])
     itemsize = jnp.dtype(jnp.int32).itemsize
+    tb = batch_tile(block_b)
     if pipeline:
-        tiles = itemsize * (2 * block_b * n_in          # inbuf slots
-                            + 2 * block_b * n_out_last  # outbuf slots
-                            + block_b * max_width)      # activations
+        tiles = itemsize * (2 * slot_rows(n_in) * tb    # inbuf slots
+                            + 2 * slot_rows(n_out_last) * tb  # outbuf
+                            + rows * tb)                # activations
     else:
-        tiles = itemsize * (block_b * n_in              # in block
-                            + block_b * n_out_last      # out block
-                            + block_b * max_width)      # activations
+        tiles = itemsize * (n_in * tb                   # in block
+                            + n_out_last * tb           # out block
+                            + rows * tb)                # activations
     return slab + tiles
 
 
@@ -579,8 +589,8 @@ def lut_network_fused_sharded(tables: List, codes: jnp.ndarray,
             return lut_network(tables, c, force_interpret=force_interpret)
 
     spec = _mesh_batch_spec(mesh)
-    out = shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
-                    check_rep=False)(codes)
+    out = jax.shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec,
+                        check_vma=False)(codes)
     return out[:B]
 
 

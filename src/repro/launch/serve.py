@@ -531,6 +531,8 @@ def main() -> None:
     ap.add_argument("--kv-int8", action="store_true",
                     help="serve with the int8 KV cache")
     args = ap.parse_args()
+    from repro.xla_env import use_compile_cache
+    use_compile_cache()
 
     if args.lut:
         serve_lut(args)

@@ -330,7 +330,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="worker-local artifact store directory")
     ap.add_argument("--bind", default="127.0.0.1")
     args = ap.parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.xla_env import use_compile_cache
+    use_compile_cache()
     os.makedirs(args.store, exist_ok=True)
     srv = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
@@ -365,12 +366,33 @@ class WorkerDied(ConnectionClosed):
     """The worker process (or its connection) went away."""
 
 
+def _check_parent_holds_no_accelerator() -> None:
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"spawn_worker: this process already holds the {backend} "
+            f"backend, so a worker process cannot own an accelerator; "
+            f"start workers from a root process that has not run JAX")
+
+
 def spawn_worker(store_dir: str, *, ready_timeout_s: float = 30.0
                  ) -> Tuple[subprocess.Popen, int]:
     """Launch a worker subprocess and wait for its READY line.  The
     child inherits the parent env (JAX_PLATFORMS / XLA_FLAGS — virtual
-    host devices propagate) with ``src/`` guaranteed on PYTHONPATH."""
+    host devices propagate) with ``src/`` guaranteed on PYTHONPATH.
+
+    An accelerator belongs to one process: a parent that has already
+    started a non-CPU JAX backend holds it, and a child could neither
+    take it nor run on it, so that case is refused up front."""
     import repro
+
+    _check_parent_holds_no_accelerator()
 
     # namespace-package safe: repro.__file__ is None under src/ layout
     src_root = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))

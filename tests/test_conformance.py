@@ -180,10 +180,11 @@ CORNERS = [
                          degree=1, adder_width=2), 21, 32),
     ("deep4", dict(in_features=16, widths=(40, 24, 16, 5), bits=2,
                    fan_in=3, degree=1, adder_width=2), 257, 64),
-    # 65 batch tiles: past PIPELINE_UNROLL_MAX_TILES, so the pipelined
-    # engine takes the ROLLED fori_loop path (dynamic buffer slots)
+    # 33 batch tiles of 128 lanes: past PIPELINE_UNROLL_MAX_TILES, so
+    # the pipelined engine takes the ROLLED fori_loop path (dynamic
+    # buffer slots)
     ("pipeline-rolled", dict(in_features=10, widths=(8, 4), bits=2,
-                             fan_in=2, degree=1, adder_width=2), 257, 4),
+                             fan_in=2, degree=1, adder_width=2), 4097, 128),
 ]
 
 
