@@ -20,7 +20,17 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import pytest
 
+from time_limit import time_limit
+
 jax.config.update("jax_enable_x64", False)
+
+
+@pytest.fixture(autouse=True)
+def _per_test_time_limit():
+    """A hung test fails by name, with a traceback, after
+    ``time_limit.TEST_TIME_LIMIT_S``."""
+    with time_limit():
+        yield
 
 
 @pytest.fixture

@@ -4,9 +4,8 @@ The artifact is the deployment handoff unit, so the bar is BIT
 exactness: for any synthesised network (packed uint8 or legacy int32
 tables, int4-nibble or raw slab encoding), save -> load -> fused /
 sharded forward must equal the in-memory synthesis output code for
-code, across {1, 2, 4} virtual devices.  Property-tested via
-hypothesis when installed, with a deterministic seeded sweep that runs
-regardless; plus the negative paths a deployable format must refuse
+code, across {1, 2, 4} virtual devices, over a seeded sweep of draws;
+plus the negative paths a deployable format must refuse
 loudly: content-hash mismatch, truncated slab file, future schema
 version.
 """
@@ -25,12 +24,6 @@ from repro.artifact import store as A
 from repro.core import lut_synth as LS
 from repro.core import lutdnn as LD
 from repro.kernels.lut_gather import ops as lg_ops, ref as lg_ref
-
-try:
-    from hypothesis import HealthCheck, given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
 
 SPEC_KW = dict(in_features=16, widths=(24, 12, 5), bits=2, fan_in=3,
                degree=1, adder_width=2)
@@ -115,28 +108,26 @@ def _check_one(art_root, B, ndev, pack, int4, seed):
     assert np.array_equal(np.asarray(got), want), (B, ndev, pack, int4)
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=12, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(B=st.integers(min_value=1, max_value=97),
-           ndev=st.sampled_from([1, 2, 4]),
-           pack=st.booleans(), int4=st.booleans(),
-           seed=st.integers(min_value=0, max_value=2 ** 16))
-    def test_property_artifact_roundtrip_sharded(
-            tmp_path_factory, B, ndev, pack, int4, seed):
-        _check_one(str(tmp_path_factory.getbasetemp() / "prop"),
-                   B, ndev, pack, int4, seed)
-
-
-def test_seeded_sweep_artifact_roundtrip(art_root):
-    """Deterministic stand-in for the hypothesis property (always
-    runs): random (B, ndev, pack, int4) draws hit remainder batches on
+def _sweep_draws(n):
+    """n seeded (B, ndev, pack, int4, seed) draws: remainder batches on
     every device count and both slab encodings."""
     rng = np.random.default_rng(4321)
-    for _ in range(8):
-        _check_one(art_root, int(rng.integers(1, 98)),
-                   int(rng.choice([1, 2, 4])), bool(rng.integers(2)),
-                   bool(rng.integers(2)), int(rng.integers(100)))
+    return [(int(rng.integers(1, 98)), int(rng.choice([1, 2, 4])),
+             bool(rng.integers(2)), bool(rng.integers(2)),
+             int(rng.integers(100)))
+            for _ in range(n)]
+
+
+SWEEP = _sweep_draws(12)
+
+
+@pytest.mark.parametrize(
+    "B,ndev,pack,int4,seed", SWEEP,
+    ids=[f"B{B}-{nd}d-{'uint8' if p else 'int32'}-{'int4' if i else 'raw'}"
+         f"-s{s}" for B, nd, p, i, s in SWEEP])
+def test_seeded_sweep_artifact_roundtrip(art_root, B, ndev, pack, int4,
+                                         seed):
+    _check_one(art_root, B, ndev, pack, int4, seed)
 
 
 def test_make_network_fn_accepts_artifact(art_root):

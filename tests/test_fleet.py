@@ -26,8 +26,8 @@ injects faults against:
   the cutover with all replicas still serving (and tagging) the old
   version.
 
-The long soak (kill + corrupt + repeated swaps under one continuous
-stream) is ``@pytest.mark.slow`` to keep the fast tier-1 lane fast.
+The soak runs kill + corrupt + repeated swaps under one continuous
+stream.
 """
 import functools
 import pathlib
@@ -473,7 +473,6 @@ def test_fleet_sheds_provably_late_request_typed(artifacts):
 # soak: every fault class under one continuous stream
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_fleet_soak_kill_corrupt_swap_zero_drops(artifacts):
     """Long Poisson stream over a 4-replica fleet while: a fetch
     corruption hits a replica during the v0->v1 swap's prepare, a

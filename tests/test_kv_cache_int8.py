@@ -10,7 +10,6 @@ from repro.models import lm as LM
 from repro.models import registry as R
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma3-12b"])
 def test_int8_cache_matches_bf16_within_quant_noise(arch):
     cfg = R.get_config(arch, smoke=True)

@@ -19,12 +19,6 @@ from repro.core import lut_synth as LS
 from repro.core import lutdnn as LD
 from repro.kernels.lut_gather import ops as lg_ops, ref as lg_ref
 
-try:                      # property tests ride hypothesis when present;
-    from hypothesis import HealthCheck, given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:       # the deterministic sweep below runs regardless
-    HAVE_HYPOTHESIS = False
-
 SPEC_KW = dict(in_features=16, widths=(24, 12, 5), bits=2, fan_in=3,
                degree=1, adder_width=2)
 
@@ -73,27 +67,25 @@ def _check_one(B, ndev, pack, seed):
     assert np.array_equal(np.asarray(got), want), (B, ndev, pack, seed)
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=12, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
-    @given(B=st.integers(min_value=1, max_value=97),
-           ndev=st.sampled_from([1, 2, 4]),
-           pack=st.booleans(),
-           seed=st.integers(min_value=0, max_value=2 ** 16))
-    def test_property_sharded_matches_single_device_oracle(
-            B, ndev, pack, seed):
-        _check_one(B, ndev, pack, seed)
-
-
-def test_seeded_sweep_sharded_matches_single_device_oracle():
-    """Deterministic stand-in for the hypothesis property (always runs,
-    with or without hypothesis): random (B, ndev, pack) draws hit
-    remainder batches on every device count."""
+def _sweep_draws(n):
+    """n seeded (B, ndev, pack, seed) draws: remainder batches on every
+    device count, both table dtypes."""
     rng = np.random.default_rng(1234)
-    for trial in range(8):
-        B = int(rng.integers(1, 98))
-        ndev = int(rng.choice([1, 2, 4]))
-        _check_one(B, ndev, bool(rng.integers(2)), int(rng.integers(100)))
+    return [(int(rng.integers(1, 98)), int(rng.choice([1, 2, 4])),
+             bool(rng.integers(2)), int(rng.integers(100)))
+            for _ in range(n)]
+
+
+SWEEP = _sweep_draws(12)
+
+
+@pytest.mark.parametrize(
+    "B,ndev,pack,seed", SWEEP,
+    ids=[f"B{B}-{nd}d-{'uint8' if p else 'int32'}-s{s}"
+         for B, nd, p, s in SWEEP])
+def test_seeded_sweep_sharded_matches_single_device_oracle(
+        B, ndev, pack, seed):
+    _check_one(B, ndev, pack, seed)
 
 
 def test_sharded_per_layer_engine_also_exact(lut_mesh):

@@ -1,19 +1,9 @@
-"""Algorithm 1 (weight mapping) invariants — unit + hypothesis.
-
-Property tests ride hypothesis when it is installed; each property also
-has a seeded stand-in that ALWAYS runs, so the Alg.-1 invariants stay
-pinned on minimal environments too.
-"""
+"""Algorithm 1 (weight mapping) invariants: unit tests, and each
+property over a sweep of seeded random draws."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # pragma: no cover - depends on environment
-    HAVE_HYPOTHESIS = False
 
 from repro.core import masking
 
@@ -47,42 +37,26 @@ def _final_mask_topk_exact(n_in, n_out, f, seed):
             assert sel.min() >= unsel.max() - 1e-6
 
 
-if HAVE_HYPOTHESIS:
-    @given(n_in=st.integers(2, 64), n_out=st.integers(1, 16),
-           fi=st.integers(1, 64), seed=st.integers(0, 2 ** 16))
-    @settings(max_examples=30, deadline=None)
-    def test_init_theta_fan_in(n_in, n_out, fi, seed):
-        _init_theta_fan_in(n_in, n_out, fi, seed)
-
-    @given(n_in=st.integers(2, 48), n_out=st.integers(1, 12),
-           f=st.integers(1, 8), seed=st.integers(0, 999))
-    @settings(max_examples=30, deadline=None)
-    def test_random_mask_exact_fan_in(n_in, n_out, f, seed):
-        _random_mask_exact_fan_in(n_in, n_out, f, seed)
-
-    @given(n_in=st.integers(4, 40), n_out=st.integers(1, 10),
-           f=st.integers(1, 6), seed=st.integers(0, 999))
-    @settings(max_examples=30, deadline=None)
-    def test_final_mask_topk_exact(n_in, n_out, f, seed):
-        _final_mask_topk_exact(n_in, n_out, f, seed)
-
-
-def test_masking_properties_seeded():
-    """Seeded stand-in for the hypothesis properties (always runs)."""
+def _property_draws(n):
+    """n seeded argument draws, one per property, each over the full
+    range the property allows."""
     rng = np.random.default_rng(0)
-    for _ in range(10):
-        _init_theta_fan_in(int(rng.integers(2, 65)),
-                           int(rng.integers(1, 17)),
-                           int(rng.integers(1, 65)),
-                           int(rng.integers(0, 2 ** 16)))
-        _random_mask_exact_fan_in(int(rng.integers(2, 49)),
-                                  int(rng.integers(1, 13)),
-                                  int(rng.integers(1, 9)),
-                                  int(rng.integers(0, 1000)))
-        _final_mask_topk_exact(int(rng.integers(4, 41)),
-                               int(rng.integers(1, 11)),
-                               int(rng.integers(1, 7)),
-                               int(rng.integers(0, 1000)))
+    return [((int(rng.integers(2, 65)), int(rng.integers(1, 17)),
+              int(rng.integers(1, 65)), int(rng.integers(0, 2 ** 16))),
+             (int(rng.integers(2, 49)), int(rng.integers(1, 13)),
+              int(rng.integers(1, 9)), int(rng.integers(0, 1000))),
+             (int(rng.integers(4, 41)), int(rng.integers(1, 11)),
+              int(rng.integers(1, 7)), int(rng.integers(0, 1000))))
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("theta_args,random_args,final_args",
+                         _property_draws(30),
+                         ids=[f"draw{i}" for i in range(30)])
+def test_masking_properties_seeded(theta_args, random_args, final_args):
+    _init_theta_fan_in(*theta_args)
+    _random_mask_exact_fan_in(*random_args)
+    _final_mask_topk_exact(*final_args)
 
 
 def test_init_dense_when_none():

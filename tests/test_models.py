@@ -15,7 +15,6 @@ from repro.models import registry as R
 ARCH_IDS = list(R.ARCHS)
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_arch_smoke_train_step(arch):
     """REQUIRED per assignment: reduced config, one train step on CPU,
@@ -48,7 +47,6 @@ def test_arch_smoke_forward_shapes(arch):
     assert np.isfinite(np.asarray(logits)).all()
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma3-12b",
                                   "recurrentgemma-9b", "rwkv6-3b",
                                   "granite-moe-1b-a400m"])
@@ -80,7 +78,6 @@ def test_prefill_decode_matches_full_forward(arch):
                                    rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.slow
 def test_local_ring_buffer_matches_sliding_window():
     """Decode with the O(window) ring cache == full sliding-window
     attention (gemma3-style local layers)."""

@@ -24,9 +24,8 @@ SAME contracts across a real process boundary with real faults:
   leaves the routing set with an epoch bump; membership epochs count
   every join and death.
 
-Worker spawns cost seconds each, so the fast lane keeps fleets small;
-the 4-worker soak (every fault class under one Poisson stream) is
-``@pytest.mark.slow``.
+Worker spawns cost seconds each, so fleets stay small; one 4-worker
+soak runs every fault class under one Poisson stream.
 """
 import functools
 import threading
@@ -352,7 +351,6 @@ def test_no_mixed_version_microbatch_across_processes(artifacts):
 # soak: every process-fault class under one stream
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_process_fleet_soak_kill_partition_corrupt_swap(artifacts):
     """4 real workers under one continuous Poisson stream while: a
     slab corruption hits a transfer during the v0->v1 swap, a worker

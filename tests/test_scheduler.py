@@ -10,7 +10,7 @@ Layered contracts:
     flush history exists — no estimate, no shed);
   * **work-stealing** — an idle batcher executes a backlogged sibling's
     overflow flushes bit-exactly, through the StealGroup of a registry;
-  * **SLO attainment (@slow)** — under mixed 2-tier Poisson load at
+  * **SLO attainment** — under mixed 2-tier Poisson load at
     1.5x the sustainable rate: interactive deadline attainment >= 95%
     over admitted requests, every shed typed, zero silent drops, zero
     hung handles, batch-tier throughput >= 0.7x the FIFO baseline.
@@ -523,10 +523,9 @@ def test_capacity_accounting_reports_live_estimates():
 
 
 # ---------------------------------------------------------------------------
-# the SLO-attainment harness (the acceptance contract; @slow)
+# the SLO-attainment harness (the acceptance contract)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 def test_slo_attainment_under_overload():
     """Mixed 2-tier Poisson load well past the sustainable rate (the
     contract floor is 1.5x; this drives 2x so the backlog provably

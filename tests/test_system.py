@@ -88,7 +88,6 @@ def test_full_toolflow_search_train_synthesise_serve(jsc):
     assert agree.mean() > 0.95
 
 
-@pytest.mark.slow
 def test_paper_claim_optimized_connectivity_beats_random(jsc):
     """Table VII, reduced: SparseLUT mask >= mean(random masks).
 
@@ -112,7 +111,6 @@ def test_paper_claim_optimized_connectivity_beats_random(jsc):
     assert np.mean(opt_accs) >= np.mean(rand_accs) - 0.01
 
 
-@pytest.mark.slow
 def test_connectivity_search_fan_in2_anomaly(jsc):
     """The (former) fan_in=2 anomaly, now a positive claim test — the
     same protocol as the fan_in=3 claim test above (seed-averaged
@@ -246,7 +244,6 @@ def test_cost_model_reproduces_paper_latency_ordering():
     assert r_shallow.latency_ns < r_deep.latency_ns
 
 
-@pytest.mark.slow
 def test_sparse_ffn_lm_integration():
     """The paper's controller embedded in the LM substrate: fan-in hits
     the target while the loss still falls."""
