@@ -14,10 +14,12 @@ HBM/VMEM, and inference becomes integer gathers:
 Every kernel runs FEATURE-MAJOR: activation codes are (features,
 batch) with the batch on the 128 vector lanes, and each layer is
 computed 128 batch lanes at a time.  The TPU's vector gather
-(``jnp.take_along_axis`` along the lane axis) reads one 128-entry row
-per output row, so a (neuron, sub-neuron) table of K entries is looked
-up in K/128 lane-gathers, each followed by a select on the chunk
-number; no (TB, TN, A, K) broadcast of the tables is ever built.
+(``jnp.take_along_axis`` along the lane axis) reads one 128-lane row of
+32-bit elements per output row, so a (neuron, sub-neuron) table row of
+Ks stored elements is looked up in ceil(Ks/128) lane-gathers, each
+followed by a select on the chunk number; byte tables are read as
+32-bit words of four bytes where that cuts the chunks (*Slab packing*),
+and no (TB, TN, A, K) broadcast of the tables is ever built.
 Routing and the adder-address pack are small float32 matmuls on the
 MXU, exact while the packed address stays under 2**24:
 
@@ -67,12 +69,26 @@ as (TN*A, F), both free row-major reshapes.  Slabs whose codes fit
 first, table axis halved to (TN, A, K//2) — the same
 two-codes-per-byte layout repro/artifact persists on disk, so a
 cold-loaded ``encoding: int4`` slab flows into the kernel with no
-expansion anywhere.  The unpack is a shift/mask at lookup time: entry
-``i`` of a row reads byte ``i >> 1`` and extracts nibble ``i & 1`` via
-``(byte >> 4*(i & 1)) & 0xF``.  K = 2**(b_in*F) and Ka = 2**(A*b_sub)
-are always even, so slab rows never straddle a byte.  Packing halves
-table residency, which is exactly the ``ops.fused_vmem_bytes`` term
-that gates fusion eligibility (``ops.can_fuse``).
+expansion anywhere.  K = 2**(b_in*F) and Ka = 2**(A*b_sub) are always
+even, so slab rows never straddle a byte.  Packing halves table
+residency, which is exactly the ``ops.fused_vmem_bytes`` term that
+gates fusion eligibility (``ops.can_fuse``).
+
+A byte slab (uint8, or int4 nibble bytes) whose rows span more 128-lane
+chunks as bytes than as 32-bit words — ceil(Ks/128) > ceil(Ks/512),
+i.e. rows over 128 bytes — is bound as WORDS: ``lookup_slab`` repacks
+each row's bytes with explicit shifts, ``b0 | b1<<8 | b2<<16 | b3<<24``,
+into a (rows, Ks/4) int32 array, once on the host when the engine is
+built (in the graph only for traced slabs).  The bytes, their order and
+the VMEM claim are the same; a gathered lane now carries four bytes
+instead of one widened byte, so a 4,096-entry uint8 adder row costs 8
+gathers instead of 32.  Other slabs (int32, or rows of at most 128
+bytes) are bound as stored.  One rule then reads every layout: a slab
+element holds e = (element bits) / (entry bits) entries, lowest first
+(1 for whole uint8 or int32 entries, 2 for nibble bytes, 4 or 8 for
+words of bytes or nibbles); entry ``i`` lives in element ``i >> log2(e)``
+and is extracted after the gather as
+``(elem >> bits*(i & (e-1))) & (2**bits - 1)``.
 
 **Scratch staging.**  The fused kernel stages inter-layer activation
 codes through ONE (stage_rows, TB) int32 VMEM scratch buffer, as tall
@@ -228,28 +244,80 @@ def _route(act, route, in_bits: int, matmul_route: bool):
     return idx
 
 
-def _lookup(tab_ref, pos, packed: bool):
-    """Row-wise table lookup: ``out[r, j] = table[r, pos[r, j]]``.
-    tab_ref: (R, Ks) table ref (Ks = K//2 bytes when nibble-packed);
-    pos: (R, L) int32 logical entries -> (R, L) int32.
+def lane_chunks(width: int) -> int:
+    """128-lane gathers that read a slab row of ``width`` elements."""
+    return max(1, -(-width // LANES))
 
-    The table row is read 128 entries at a time: each chunk is one
-    lane-gather over every row, kept where the entry falls in that
-    chunk.  Rows narrower than 128 lanes are zero-padded in VMEM."""
+
+def word_packs(width: int, itemsize: int) -> bool:
+    """Whether a slab row of ``width`` elements of ``itemsize`` bytes is
+    looked up as 32-bit words: byte slabs only, whole words only, and
+    only where the words span fewer 128-lane chunks than the bytes."""
+    return (itemsize == 1 and width % 4 == 0
+            and lane_chunks(width) > lane_chunks(width // 4))
+
+
+def lookup_slab(slab, nibbles: bool):
+    """The array ``_lookup`` reads for a table slab, and the bit width of
+    one entry in it: ``(array, bits)``.
+
+    slab: (..., Ks) uint8 or int32, one entry per element, or two per
+    byte when ``nibbles`` (int4, low nibble first).  Where
+    ``word_packs`` holds, each row's bytes become int32 words
+    ``b0 | b1 << 8 | b2 << 16 | b3 << 24`` (shape (..., Ks // 4)): the
+    same bytes, in the same order on every backend.  Numpy repacks a
+    concrete slab once; a traced slab is repacked in the graph."""
+    bits = 4 if nibbles else 8 * slab.dtype.itemsize
+    width = slab.shape[-1]
+    if not word_packs(width, slab.dtype.itemsize):
+        return slab, bits
+    xp = jnp if isinstance(slab, jax.core.Tracer) else np
+    b = xp.asarray(slab).astype(xp.int32).reshape(
+        slab.shape[:-1] + (width // 4, 4))
+    words = b[..., 0] | b[..., 1] << 8 | b[..., 2] << 16 | b[..., 3] << 24
+    return jnp.asarray(words), bits
+
+
+def lookup_row_chunks(shape, itemsize: int) -> int:
+    """Lane-gather row-chunks of one lookup per 128-row batch group in a
+    slab of ``shape`` (rows ``shape[:-1]``, ``shape[-1]`` elements of
+    ``itemsize`` bytes per row), as ``lookup_slab`` lays it out."""
+    width = shape[-1]
+    if word_packs(width, itemsize):
+        width //= 4
+    return int(np.prod(shape[:-1])) * lane_chunks(width)
+
+
+def _lookup(tab_ref, pos, bits: int):
+    """Row-wise table lookup: ``out[r, j] = table[r, pos[r, j]]``.
+    tab_ref: (R, Ks) slab from ``lookup_slab`` whose elements each hold
+    e = (element bits) / ``bits`` entries, lowest first: e = 1 for a
+    uint8 or int32 slab of whole entries, 2 for int4 nibble bytes, 4 or
+    8 for int32 words of bytes or nibbles; pos: (R, L) int32 logical
+    entries -> (R, L) int32.
+
+    Entry ``pos`` lives in element ``pos >> log2(e)``, field
+    ``pos & (e - 1)``.  The row is read 128 elements at a time: each
+    chunk is one lane-gather over every row, kept where the element
+    falls in that chunk; the field is extracted once, after the select
+    loop (``(out >> field * bits) & (2**bits - 1)``: the shift of an
+    int32 word is arithmetic, the mask makes it exact).  Rows narrower
+    than 128 lanes are zero-padded in VMEM."""
     Ks = tab_ref.shape[1]
-    byte = pos >> 1 if packed else pos
+    log_e = (8 * tab_ref.dtype.itemsize // bits).bit_length() - 1
+    elem = pos >> log_e
 
     def gather(t, lo):
-        t = t.astype(jnp.int32)
+        t = t.astype(jnp.int32)                      # no-op on words
         if t.shape[1] < LANES:
             t = jnp.pad(t, ((0, 0), (0, LANES - t.shape[1])))
         return jnp.take_along_axis(t, lo, axis=1)
 
     if Ks <= LANES:
-        out = gather(tab_ref[...], byte)
+        out = gather(tab_ref[...], elem)
     else:
         n_full, tail = divmod(Ks, LANES)
-        lo, hi = byte & (LANES - 1), byte >> 7       # byte // LANES
+        lo, hi = elem & (LANES - 1), elem >> 7       # elem // LANES
 
         def chunk(c, acc):
             start = pl.multiple_of(c * LANES, LANES)
@@ -260,24 +328,25 @@ def _lookup(tab_ref, pos, packed: bool):
         if tail:
             g = gather(tab_ref[:, n_full * LANES:], lo)
             out = jnp.where(hi == n_full, g, out)
-    if packed:
-        out = (out >> ((pos & 1) * 4)) & 0xF
+    if log_e:
+        field = pos & ((1 << log_e) - 1)
+        out = (out >> (field * bits)) & ((1 << bits) - 1)
     return out
 
 
 def _layer_compute(act, route_ref, sub_ref, add_ref, *, in_bits: int,
                    sub_bits: int, use_adder: bool, matmul_route: bool,
-                   sub_packed: bool, add_packed: bool):
+                   sub_field: int, add_field: int):
     """One LUT layer on one 128-lane batch group.
 
     act: (n_in, L) int32 codes; route_ref: (n_in, TN*A) float32
     routing matrix when ``matmul_route``, else (TN*A, F) int32 conn;
-    sub_ref: (TN*A, Ks) uint8|int32 sub tables, row ``n*A + a``
-    (Ks = K//2 when ``sub_packed``); add_ref: (TN, Kas) adder tables
-    (halved likewise under ``add_packed``).  Returns (TN, L) int32.
+    sub_ref: (TN*A, Ks) sub-table slab from ``lookup_slab``, row
+    ``n*A + a``, entries ``sub_field`` bits wide; add_ref: (TN, Kas)
+    adder slab, entries ``add_field`` bits wide.  Returns (TN, L) int32.
     """
     idx = _route(act, route_ref[...], in_bits, matmul_route)
-    sub = _lookup(sub_ref, idx, sub_packed)                  # (TN*A, L)
+    sub = _lookup(sub_ref, idx, sub_field)                   # (TN*A, L)
     if not use_adder:
         return sub
     # PolyLUT-Add: pack each neuron's A sub-codes (rows n*A + a) into
@@ -292,7 +361,7 @@ def _layer_compute(act, route_ref, sub_ref, add_ref, *, in_bits: int,
         place = jnp.where(col == row * A + a,
                           float(1 << (sub_bits * a)), place)
     aidx = _dot(place, sub.astype(jnp.float32)).astype(jnp.int32)
-    return _lookup(add_ref, aidx, add_packed)
+    return _lookup(add_ref, aidx, add_field)
 
 
 def _for_lane_groups(n_lanes: int, body):
@@ -305,13 +374,12 @@ def _for_lane_groups(n_lanes: int, body):
 
 def _lut_kernel(codes_ref, conn_ref, sub_ref, add_ref, out_ref,
                 *, in_bits: int, sub_bits: int, use_adder: bool,
-                sub_packed: bool, add_packed: bool):
+                sub_field: int, add_field: int):
     def body(cols):
         out_ref[:, cols] = _layer_compute(
             codes_ref[:, cols], conn_ref, sub_ref, add_ref,
             in_bits=in_bits, sub_bits=sub_bits, use_adder=use_adder,
-            matmul_route=False, sub_packed=sub_packed,
-            add_packed=add_packed)
+            matmul_route=False, sub_field=sub_field, add_field=add_field)
 
     _for_lane_groups(codes_ref.shape[1], body)
 
@@ -329,10 +397,6 @@ def _nbytes(shape, dtype) -> int:
     return int(np.prod(shape)) * jnp.dtype(dtype).itemsize
 
 
-@functools.partial(jax.jit, static_argnames=("in_bits", "sub_bits",
-                                             "block_b", "block_n",
-                                             "interpret",
-                                             "sub_packed", "add_packed"))
 def lut_gather_pallas(codes: jnp.ndarray, conn: jnp.ndarray,
                       sub_table: jnp.ndarray, add_table: jnp.ndarray,
                       in_bits: int, sub_bits: int,
@@ -346,15 +410,33 @@ def lut_gather_pallas(codes: jnp.ndarray, conn: jnp.ndarray,
     ``sub_packed`` / ``add_packed`` declare int4 nibble-packed slabs
     (table axis halved, unpacked in-kernel).  Returns (B, n_out) int32.
     Neuron tiles of ``block_n`` (a multiple of 32, the uint8 sublane
-    tile) sit on the sublane axis of every block.
+    tile) sit on the sublane axis of every block.  The slabs are laid
+    out by ``lookup_slab`` before the jitted call.
     """
+    # an adder-off layer's add slab is by definition unread, so its
+    # packing flag is forced off
+    sub_table, sub_field = lookup_slab(sub_table, sub_packed)
+    add_table, add_field = lookup_slab(
+        add_table, add_packed and add_table.shape[-1] > 0)
+    return _lut_gather_call(codes, conn, sub_table, add_table,
+                            in_bits=in_bits, sub_bits=sub_bits,
+                            block_b=block_b, block_n=block_n,
+                            interpret=interpret, sub_field=sub_field,
+                            add_field=add_field)
+
+
+@functools.partial(jax.jit, static_argnames=("in_bits", "sub_bits",
+                                             "block_b", "block_n",
+                                             "interpret",
+                                             "sub_field", "add_field"))
+def _lut_gather_call(codes, conn, sub_table, add_table, *, in_bits: int,
+                     sub_bits: int, block_b: int, block_n: int,
+                     interpret: bool, sub_field: int, add_field: int):
     B, n_in = codes.shape
     n_out, A, F = conn.shape
     # adder on/off is decided by the REAL table's width, before the
-    # zero-width dummy is substituted; an adder-off layer's add slab is
-    # by definition unread, so its packing flag is forced off too
+    # zero-width dummy is substituted
     use_adder = add_table.shape[-1] > 0
-    add_packed = add_packed and use_adder
 
     TB = batch_tile(block_b, B)
     TN = n_out if n_out <= block_n else block_n
@@ -372,8 +454,7 @@ def lut_gather_pallas(codes: jnp.ndarray, conn: jnp.ndarray,
 
     kernel = functools.partial(_lut_kernel, in_bits=in_bits,
                                sub_bits=sub_bits, use_adder=use_adder,
-                               sub_packed=sub_packed,
-                               add_packed=add_packed)
+                               sub_field=sub_field, add_field=add_field)
     blocks = [((n_in, TB), jnp.int32), ((TN * A, F), conn.dtype),
               ((TN * A, Ks), sub_table.dtype), ((TN, Kas), add_table.dtype),
               ((TN, TB), jnp.int32)]
@@ -411,12 +492,11 @@ def _run_layers(refs, metas, load_in, store_out, scratch):
     def body(cols):
         act = load_in(cols)
         for l, (in_bits, sub_bits, use_adder, n_in, n_out, mm,
-                sub_packed, add_packed) in enumerate(metas):
+                sub_field, add_field) in enumerate(metas):
             out = _layer_compute(
                 act, refs[1 + 3 * l], refs[2 + 3 * l], refs[3 + 3 * l],
                 in_bits=in_bits, sub_bits=sub_bits, use_adder=use_adder,
-                matmul_route=mm, sub_packed=sub_packed,
-                add_packed=add_packed)
+                matmul_route=mm, sub_field=sub_field, add_field=add_field)
             if l == n_layers - 1:
                 store_out(cols, out)
             else:
@@ -427,15 +507,16 @@ def _run_layers(refs, metas, load_in, store_out, scratch):
 
 
 def _fused_kernel(*refs, metas: Tuple[Tuple[int, int, bool, int, int,
-                                            bool, bool, bool], ...]):
+                                            bool, int, int], ...]):
     """refs = [codes, (route, sub, add) * L, out, scratch].
 
     metas[l] = (in_bits, sub_bits, use_adder, n_in, n_out, matmul_route,
-    sub_packed, add_packed) — static.  route is the (n_in, n_out*A)
-    float32 routing matrix when matmul_route else the (n_out*A, F)
-    int32 conn.  Inter-layer activation codes are staged through the
-    (stage_rows, TB) int32 VMEM scratch; only the input read and output
-    write touch HBM.
+    sub_field, add_field) — static; a field is the bit width of one
+    entry of the slab as ``lookup_slab`` laid it out.  route is the
+    (n_in, n_out*A) float32 routing matrix when matmul_route else the
+    (n_out*A, F) int32 conn.  Inter-layer activation codes are staged
+    through the (stage_rows, TB) int32 VMEM scratch; only the input read
+    and output write touch HBM.
     """
     n_layers = len(metas)
     codes_ref = refs[0]
@@ -547,7 +628,7 @@ def _kernel_layout(flat_tables, metas):
 def lut_network_fused_pallas(codes: jnp.ndarray,
                              flat_tables: Tuple[jnp.ndarray, ...],
                              metas: Tuple[Tuple[int, int, bool, int, int,
-                                                bool, bool, bool], ...],
+                                                bool, int, int], ...],
                              block_b: int = 256,
                              interpret: bool = False,
                              pipeline: bool = False) -> jnp.ndarray:
@@ -556,9 +637,10 @@ def lut_network_fused_pallas(codes: jnp.ndarray,
     codes: (B, n_in) int32.  flat_tables: (route_l, sub_l, add_l) for
     each layer, concatenated — route_l is the matmul routing matrix or
     the conn array, per metas[l] = (in_bits, sub_bits, use_adder, n_in,
-    n_out, matmul_route, sub_packed, add_packed).  Returns
-    (B, n_out_last) int32.  Empty adder tables must be pre-replaced by
-    ``dummy_add_table`` (ops.lut_network_fused does this).
+    n_out, matmul_route, sub_field, add_field).  Returns
+    (B, n_out_last) int32.  Slabs come laid out by ``lookup_slab`` and
+    empty adder tables pre-replaced by ``dummy_add_table``
+    (ops._flatten_network does both).
     ``pipeline=True`` switches from the grid-per-tile path to the
     double-buffered in-kernel tile loop (module docstring, "Tile
     pipeline").

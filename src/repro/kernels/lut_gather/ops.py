@@ -6,7 +6,9 @@ layer, and ``lut_network_fused`` runs it in a SINGLE pallas_call —
 every table slab VMEM-resident, inter-layer codes in VMEM scratch, one
 HBM read + one HBM write per forward pass.  int4 nibble-packed slabs
 (lut_synth.pack_tables_int4 or a packed artifact load) stay packed in
-VMEM and unpack per lookup in-kernel, halving table residency;
+VMEM and unpack per lookup in-kernel, halving table residency (byte
+slabs with rows over 128 bytes are bound as 32-bit words, four bytes a
+lane: 8 gathers instead of 32 for a 4,096-byte row);
 ``pipeline=True`` double-buffers the fused kernel's batch tiles so a
 tile's HBM transfers overlap its neighbour's compute; and
 ``tune_block_b`` sweeps the batch-tile size.  All paths match
@@ -55,6 +57,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.kernels.lut_gather.lut_gather import (MATMUL_ROUTE_MAX_BITS,
                                                  batch_tile,
                                                  dummy_add_table,
+                                                 lookup_row_chunks,
+                                                 lookup_slab,
                                                  lut_gather_pallas,
                                                  lut_network_fused_pallas,
                                                  routing_matrix, slot_rows,
@@ -133,7 +137,10 @@ def _infer_n_in0(tables: List, n_in0: Optional[int]) -> int:
 def _flatten_network(tables: List, n_in0: int):
     """Build the fused kernel's inputs: the flat (route, sub, add) list
     and the static metas tuple — metas[l] = (in_bits, sub_bits,
-    use_adder, n_in, n_out, matmul_route, sub_packed, add_packed).
+    use_adder, n_in, n_out, matmul_route, sub_field, add_field).
+    Slabs are laid out by ``lookup_slab`` (32-bit words where that cuts
+    the lookup's lane chunks; the same bytes), and each field is the bit
+    width of one entry there.
 
     Routing uses the matmul formulation (codes @ routing_matrix) per
     layer whenever the packed address width allows it.  The matrices
@@ -158,12 +165,25 @@ def _flatten_network(tables: List, n_in0: int):
              and not isinstance(t.conn, jax.core.Tracer))
         route = (cached if cached is not None else
                  routing_matrix(t.conn, t.in_bits, n_in) if mm else t.conn)
-        flat.extend([route, t.sub_table, add])
+        sub, sub_field = lookup_slab(t.sub_table,
+                                     getattr(t, "sub_packed", False))
+        add, add_field = lookup_slab(
+            add, use_adder and getattr(t, "add_packed", False))
+        flat.extend([route, sub, add])
         metas.append((t.in_bits, t.sub_bits, use_adder, n_in, n_out, mm,
-                      getattr(t, "sub_packed", False),
-                      use_adder and getattr(t, "add_packed", False)))
+                      sub_field, add_field))
         n_in = n_out
     return tuple(flat), tuple(metas)
+
+
+def network_row_chunks(tables: List) -> int:
+    """Lane-gather row-chunks per 128-row batch group of one forward
+    pass: summed over every sub- and adder-table lookup of ``tables``,
+    as the kernels lay the slabs out (``lookup_slab``).  The same on
+    every engine path, since each runs every lookup once."""
+    return sum(lookup_row_chunks(slab.shape, slab.dtype.itemsize)
+               for t in tables for slab in (t.sub_table, t.add_table)
+               if slab.shape[-1] > 0)
 
 
 def _tile_bytes(n_in0: int, widths: List[int], block_b: int,
@@ -682,7 +702,9 @@ def make_network_fn(tables: List, fused: Optional[bool] = None,
     observable: the returned callable carries the chosen plan as
     ``fn.execution_plan`` (mode, segment bounds, per-segment VMEM
     ledger and block_b — ``fn.execution_plan.describe()`` is the
-    one-liner ``serve --lut`` logs at model load).
+    one-liner ``serve --lut`` logs at model load), and the lookup cost
+    of the tables it serves as ``fn.lookup_row_chunks``
+    (``network_row_chunks``).
 
     ``block_b="auto"`` runs the ``tune_block_b`` sweep (probing at
     ``tune_batch``) PER SEGMENT before closing over the winners.
@@ -789,6 +811,7 @@ def make_network_fn(tables: List, fused: Optional[bool] = None,
     # padded per-shard staging copies — so apply it wherever asked
     jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
     jitted.execution_plan = eff_plan
+    jitted.lookup_row_chunks = network_row_chunks(tables)
     return jitted
 
 

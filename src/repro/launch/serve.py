@@ -474,7 +474,8 @@ def serve_lut(args) -> None:
     # artifact manifest is adopted as-is, skipping re-plan + tune)
     serve_fn = lg_ops.make_network_fn(source, block_b=args.microbatch,
                                       mesh=mesh)
-    print(f"  {serve_fn.execution_plan.describe()}")
+    print(f"  {serve_fn.execution_plan.describe()} "
+          f"lookup_row_chunks={serve_fn.lookup_row_chunks}")
     drive_lut_serving(
         serve_fn, spec, data, requests=args.requests,
         microbatch=args.microbatch, deadline_ms=args.deadline_ms,

@@ -9,11 +9,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
+from paper_tables import shaped_tables
+from repro.configs import paper_models as PM
 from repro.core import lut_synth as LS
 from repro.core import lutdnn as LD
 from repro.kernels.lut_gather import ops as lg_ops, ref as lg_ref
-from repro.kernels.lut_gather.lut_gather import routing_matrix
+from repro.kernels.lut_gather.lut_gather import (LANES, _lookup,
+                                                 lookup_slab,
+                                                 routing_matrix, word_packs)
 
 
 def _ref_chain(tables, codes):
@@ -209,3 +214,110 @@ def test_fused_falls_back_without_routing_cache():
     got = lg_ops.lut_network_fused(tables, codes)
     assert np.array_equal(np.asarray(got),
                           np.asarray(_ref_chain(tables, codes)))
+
+
+def _nibble_bytes(entries):
+    """int4 codes (R, K) -> (R, K // 2) bytes, low nibble first."""
+    return (entries[:, 0::2] | entries[:, 1::2] << 4).astype(np.uint8)
+
+
+@pytest.mark.parametrize("row_bytes", [64, 128, 256, 384, 1024, 4096])
+@pytest.mark.parametrize("bits", [4, 8, 32])
+def test_lookup_matches_numpy(bits, row_bytes):
+    """``_lookup`` on the slab ``lookup_slab`` lays out reads exactly
+    ``table[r, pos[r, j]]``: int4 nibbles, uint8 bytes and int32
+    entries, byte and word layouts, partial-chunk tails, a row count
+    off the 8-row tile, and the top field of each word with its high
+    bit set (a sign bit once packed)."""
+    R, L = 13, 128
+    K = row_bytes * 8 // bits
+    rng = np.random.default_rng(bits * 10007 + row_bytes)
+    if bits == 32:
+        table = rng.integers(-2 ** 31, 2 ** 31, (R, K), dtype=np.int64
+                             ).astype(np.int32)
+        table[:, -1] = -1
+        stored = table
+    else:
+        table = rng.integers(0, 2 ** bits, (R, K)).astype(np.int32)
+        per_word = 32 // bits
+        table[:, per_word - 1::per_word] |= 1 << (bits - 1)
+        stored = (_nibble_bytes(table) if bits == 4
+                  else table.astype(np.uint8))
+    assert stored.nbytes == R * row_bytes
+    pos = rng.integers(0, K, (R, L)).astype(np.int32)
+    pos[:, 0] = K - 1                       # top field of the last word
+    pos[:, 1] = min(K, 32 // bits) - 1      # top field of the first word
+    pos[:, 2] = 0
+
+    slab, field = lookup_slab(jnp.asarray(stored), nibbles=bits == 4)
+    assert field == bits
+    words = word_packs(stored.shape[1], stored.itemsize)
+    assert words == (bits != 32 and row_bytes > 128)
+    assert slab.dtype == (jnp.int32 if words else stored.dtype)
+    assert slab.nbytes == stored.nbytes
+
+    def kernel(tab_ref, pos_ref, out_ref):
+        out_ref[...] = _lookup(tab_ref, pos_ref[...], field)
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((R, L), jnp.int32),
+        interpret=True)(slab, jnp.asarray(pos))
+    want = np.take_along_axis(table, pos, axis=1)
+    assert np.array_equal(np.asarray(got), want)
+
+
+# execution plan summaries and fused VMEM claims of the paper rows, as
+# the planner gave them before word packing (which changes neither)
+PAPER_PLANS = {
+    "jsc-xl-add2": (16, 2589184, dict(
+        mode="fused", n_segments=1, n_in0=16, budget_bytes=12582912,
+        pipeline=False, pack_int4=False, block_b_tuned=[1024],
+        cut_widths=[], segments=[dict(layers=[0, 5], block_b=1024,
+                                      vmem_bytes=2589184, n_in=16,
+                                      n_out=5)])),
+    "hdr-add2": (784, 6553280, dict(
+        mode="fused", n_segments=1, n_in0=784, budget_bytes=12582912,
+        pipeline=False, pack_int4=False, block_b_tuned=[1024],
+        cut_widths=[], segments=[dict(layers=[0, 6], block_b=1024,
+                                      vmem_bytes=6553280, n_in=784,
+                                      n_out=10)])),
+}
+
+
+@pytest.mark.parametrize("name,spec_fn,byte_chunks,word_chunks", [
+    ("jsc-xl-add2", PM.jsc_xl_add2, 13808, 3764),
+    ("hdr-add2", PM.hdr_add2, 1998, 1998),
+], ids=["jsc-xl-add2", "hdr-add2"])
+def test_paper_width_exact_and_plan_stable(name, spec_fn, byte_chunks,
+                                           word_chunks):
+    """At the paper rows' widths and batch 256, the served engine
+    matches ``lut_synth.lut_forward`` exactly; word packing cuts
+    JSC-XL-Add2's lane-gather row-chunks 13,808 -> 3,764 and leaves
+    HDR-Add2's int4 slabs (one chunk each) at 1,998; the execution plan
+    and the VMEM claim are unchanged."""
+    spec = spec_fn()
+    plain = shaped_tables(spec, seed=3)
+    # HDR-Add2's 2/3-bit codes are served int4-packed, as its artifact
+    # loads; the reference reads the unpacked tables
+    tables = LS.pack_tables_int4(plain) if name == "hdr-add2" else plain
+    assert any(t.sub_packed for t in tables) == (name == "hdr-add2")
+    n_in, vmem, summary = PAPER_PLANS[name]
+
+    fn = lg_ops.make_network_fn(tables, n_in0=n_in)
+    assert fn.execution_plan.summary() == summary
+    assert lg_ops.fused_vmem_actual(tables, n_in0=n_in) == vmem
+    assert lg_ops.fused_vmem_bytes(tables, n_in0=n_in) == vmem
+    # one lane gather per 128 stored elements per row, before packing
+    assert sum(int(np.prod(s.shape[:-1])) * -(-s.shape[-1] // LANES)
+               for t in tables for s in (t.sub_table, t.add_table)
+               if s.shape[-1]) == byte_chunks
+    assert fn.lookup_row_chunks == word_chunks
+
+    quant = spec.layer_specs()[0].in_quant
+    x = jax.random.uniform(jax.random.key(5), (256, n_in),
+                           minval=quant.low, maxval=quant.high)
+    codes = quant.to_code(quant.clip(x)).astype(jnp.int32)
+    got = np.asarray(fn(codes))
+    want = LS.lut_forward(plain, x, quant)
+    assert np.array_equal(np.asarray(LS.OUTPUT_QUANT.from_code(got)),
+                          np.asarray(want))
