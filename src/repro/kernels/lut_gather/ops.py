@@ -54,7 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels.lut_gather.lut_gather import (MATMUL_ROUTE_MAX_BITS,
+from repro.kernels.lut_gather.lut_gather import (LANES,
+                                                 MATMUL_ROUTE_MAX_BITS,
                                                  batch_tile,
                                                  dummy_add_table,
                                                  lookup_row_chunks,
@@ -134,6 +135,22 @@ def _infer_n_in0(tables: List, n_in0: Optional[int]) -> int:
         return t0.conn.shape[2]
 
 
+def _matmul_route(t, n_in: int):
+    """Whether layer ``t``, reading ``n_in`` codes, is routed by a
+    float32 routing matrix (``codes @ routing_matrix``), and the cached
+    matrix to use: ``(matmul_route, cached or None)``.  A cache built
+    for another width is ignored; without one, the matrix is derived
+    from a concrete conn when the packed address fits
+    ``MATMUL_ROUTE_MAX_BITS``."""
+    cached = getattr(t, "routing", None)
+    if cached is not None and cached.shape[0] != n_in:
+        cached = None                        # synthesised for another width
+    mm = cached is not None or \
+        (t.in_bits * t.conn.shape[2] <= MATMUL_ROUTE_MAX_BITS
+         and not isinstance(t.conn, jax.core.Tracer))
+    return mm, cached
+
+
 def _flatten_network(tables: List, n_in0: int):
     """Build the fused kernel's inputs: the flat (route, sub, add) list
     and the static metas tuple — metas[l] = (in_bits, sub_bits,
@@ -153,16 +170,11 @@ def _flatten_network(tables: List, n_in0: int):
     flat, metas = [], []
     n_in = n_in0
     for t in tables:
-        n_out, _, fan_in = t.conn.shape
+        n_out = t.conn.shape[0]
         use_adder = t.add_table.shape[-1] > 0
         add = (t.add_table if use_adder
                else dummy_add_table(n_out, t.sub_table.dtype))
-        cached = getattr(t, "routing", None)
-        if cached is not None and cached.shape[0] != n_in:
-            cached = None                    # synthesised for another width
-        mm = cached is not None or \
-            (t.in_bits * fan_in <= MATMUL_ROUTE_MAX_BITS
-             and not isinstance(t.conn, jax.core.Tracer))
+        mm, cached = _matmul_route(t, n_in)
         route = (cached if cached is not None else
                  routing_matrix(t.conn, t.in_bits, n_in) if mm else t.conn)
         sub, sub_field = lookup_slab(t.sub_table,
@@ -184,6 +196,20 @@ def network_row_chunks(tables: List) -> int:
     return sum(lookup_row_chunks(slab.shape, slab.dtype.itemsize)
                for t in tables for slab in (t.sub_table, t.add_table)
                if slab.shape[-1] > 0)
+
+
+def network_routing_macs(tables: List, n_in0: int) -> int:
+    """Multiply-adds per row of the routing matmuls (``codes @
+    routing_matrix``, on the MXU) in one fused forward pass: the sum of
+    n_in * n_out * A over the matmul-routed layers of ``tables``.  The
+    lookups' work is ``network_row_chunks``."""
+    macs, n_in = 0, n_in0
+    for t in tables:
+        n_out, A, _ = t.conn.shape
+        if _matmul_route(t, n_in)[0]:
+            macs += n_in * n_out * A
+        n_in = n_out
+    return macs
 
 
 def _tile_bytes(n_in0: int, widths: List[int], block_b: int,
@@ -216,12 +242,7 @@ def fused_vmem_bytes(tables: List, block_b: int = 1024,
     n_in0 = n_in
     for t in tables:
         n_out, A, fan_in = t.conn.shape
-        cached = getattr(t, "routing", None)
-        if cached is not None and cached.shape[0] != n_in:
-            cached = None
-        mm = cached is not None or \
-            (t.in_bits * fan_in <= MATMUL_ROUTE_MAX_BITS
-             and not isinstance(t.conn, jax.core.Tracer))
+        mm, _ = _matmul_route(t, n_in)
         slab += (4 * n_in * n_out * A if mm
                  else 4 * n_out * A * fan_in)                 # route/conn
         slab += int(t.sub_table.size * t.sub_table.dtype.itemsize)
@@ -305,7 +326,8 @@ class SegmentPlan:
     ``mode`` is ``"fused"`` (one segment — exactly the classic fully
     fused path), ``"segmented"`` (a chain of fused pallas_calls with
     inter-segment codes staged through HBM) or ``"per_layer"`` (last
-    resort: some single layer exceeds the budget at any tile size).
+    resort: some single layer exceeds the budget even at a 128-row
+    batch tile).
     ``bounds`` are half-open ``(start, end)`` layer ranges; ``block_b``
     and ``vmem_bytes`` are the per-segment batch tile and VMEM ledger
     at that tile; ``cut_widths`` are the code widths crossing each
@@ -376,7 +398,8 @@ class SegmentPlan:
         mb = lambda b: f"{b / 2 ** 20:.2f}MiB"  # noqa: E731
         if self.mode == "per_layer":
             return (f"plan: per-layer fallback (a single layer exceeds "
-                    f"the {mb(self.budget)} fused VMEM budget)")
+                    f"the {mb(self.budget)} fused VMEM budget at "
+                    f"block_b={LANES})")
         segs = " ".join(
             f"[L{s}..L{e - 1} block_b={bb} vmem={mb(v)}]"
             for (s, e), bb, v in zip(self.bounds, self.block_b,
@@ -447,9 +470,15 @@ def plan_segments(tables: List, block_b: int = 1024,
     segments.  Degrades gracefully: a network that fits the budget
     plans to exactly ONE segment (mode ``"fused"`` — byte-identical to
     the classic fully fused path); an oversized network plans to N
-    fused segments with inter-segment codes staged through HBM; only a
-    network with a single layer too large to fuse at all falls back to
-    mode ``"per_layer"``.
+    fused segments with inter-segment codes staged through HBM.
+
+    A single layer that alone busts the budget at ``block_b`` (a
+    3,072-wide first layer's routing matrix and input tile) makes the
+    planner try again at half the batch tile, in whole 128-row groups,
+    down to 128; the plan records the tile it chose per segment.  Only
+    a layer that busts the budget at a 128-row tile falls back to mode
+    ``"per_layer"``.  A network that plans at ``block_b`` gets exactly
+    that plan.
 
     Multi-segment plans run each segment through the double-buffered
     pipelined kernel (codes already live in HBM between segments, which
@@ -469,28 +498,37 @@ def plan_segments(tables: List, block_b: int = 1024,
     tables = list(tables)
     n_in0 = _infer_n_in0(tables, n_in0)
 
-    def build(tbls):
+    def build(tbls, tile):
         pipe = pipeline
-        r = _plan_bounds(tbls, block_b, n_in0, pipe, budget)
+        r = _plan_bounds(tbls, tile, n_in0, pipe, budget)
         if r is None:
             return None
         if len(r[0]) > 1 and not pipe:
-            r2 = _plan_bounds(tbls, block_b, n_in0, True, budget)
+            r2 = _plan_bounds(tbls, tile, n_in0, True, budget)
             if r2 is not None and len(r2[0]) == len(r[0]):
                 r, pipe = r2, True
         return r, pipe
 
-    chosen, pack_int4 = build(tables), False
-    already_packed = any(getattr(t, "sub_packed", False) or
-                         getattr(t, "add_packed", False) for t in tables)
-    if prefer_int4 and not already_packed:
+    packed4 = None
+    if prefer_int4 and not any(getattr(t, "sub_packed", False) or
+                               getattr(t, "add_packed", False)
+                               for t in tables):
         from repro.core.lut_synth import pack_tables_int4
         packed4 = pack_tables_int4(tables)
-        if any(t.sub_packed or t.add_packed for t in packed4):
-            alt = build(packed4)
+        if not any(t.sub_packed or t.add_packed for t in packed4):
+            packed4 = None
+
+    tile = block_b
+    while True:
+        chosen, pack_int4 = build(tables, tile), False
+        if packed4 is not None:
+            alt = build(packed4, tile)
             if alt is not None and (chosen is None or
                                     len(alt[0][0]) < len(chosen[0][0])):
                 chosen, pack_int4 = alt, True
+        if chosen is not None or batch_tile(tile) <= LANES:
+            break
+        tile = max(LANES, batch_tile(tile) // (2 * LANES) * LANES)
 
     if chosen is None:
         return SegmentPlan(mode="per_layer", bounds=(), block_b=(),
@@ -500,7 +538,7 @@ def plan_segments(tables: List, block_b: int = 1024,
     (bounds, vmem, cuts, segw), pipe = chosen
     mode = "fused" if len(bounds) == 1 else "segmented"
     return SegmentPlan(mode=mode, bounds=bounds,
-                       block_b=(block_b,) * len(bounds), vmem_bytes=vmem,
+                       block_b=(tile,) * len(bounds), vmem_bytes=vmem,
                        cut_widths=cuts, seg_widths=segw, n_in0=n_in0,
                        budget=budget, pipeline=pipe, pack_int4=pack_int4)
 
@@ -695,16 +733,21 @@ def make_network_fn(tables: List, fused: Optional[bool] = None,
     ``fn(codes) -> out_codes`` for serving.  ``fused=None`` (the
     default) drives the engine choice through ``plan_segments``: one
     fused kernel when the tables fit VMEM, a chain of fused segments
-    when they do not, per-layer only as a last resort — pass ``n_in0``
-    (the network input width) for an exact first-layer routing-matrix
-    estimate in that decision.  ``fused=True``/``False`` force the
-    classic whole-network-fused / per-layer engines.  The decision is
+    when they do not, a smaller batch tile than ``block_b`` when a
+    single layer busts the budget at ``block_b``, per-layer only when
+    one busts it at a 128-row tile — pass ``n_in0`` (the network input
+    width) for an exact first-layer routing-matrix estimate in that
+    decision.  ``fused=True``/``False`` force the classic
+    whole-network-fused / per-layer engines.  The decision is
     observable: the returned callable carries the chosen plan as
     ``fn.execution_plan`` (mode, segment bounds, per-segment VMEM
     ledger and block_b — ``fn.execution_plan.describe()`` is the
-    one-liner ``serve --lut`` logs at model load), and the lookup cost
-    of the tables it serves as ``fn.lookup_row_chunks``
-    (``network_row_chunks``).
+    one-liner ``serve --lut`` logs at model load), and the work mix of
+    one forward pass as ``fn.lookup_row_chunks`` (lane-gather
+    row-chunks per 128-row group, ``network_row_chunks``) and
+    ``fn.routing_macs_per_row`` (routing-matmul multiply-adds per row,
+    ``network_routing_macs``; 0 on the per-layer engine, which routes
+    by conn).
 
     ``block_b="auto"`` runs the ``tune_block_b`` sweep (probing at
     ``tune_batch``) PER SEGMENT before closing over the winners.
@@ -812,6 +855,9 @@ def make_network_fn(tables: List, fused: Optional[bool] = None,
     jitted = jax.jit(fn, donate_argnums=(0,) if donate else ())
     jitted.execution_plan = eff_plan
     jitted.lookup_row_chunks = network_row_chunks(tables)
+    jitted.routing_macs_per_row = (
+        0 if plan.mode == "per_layer" else
+        network_routing_macs(tables, plan.n_in0))
     return jitted
 
 

@@ -378,6 +378,8 @@ class ModelRegistry:
                               else None),
                 "exec_segments": (e.plan.n_segments
                                   if e.plan is not None else None),
+                "exec_block_b": (list(e.plan.block_b)
+                                 if e.plan is not None else None),
                 "sheds": 0 if sched is None else sched.sheds,
             }
             if self.steal_group is not None:

@@ -475,7 +475,8 @@ def serve_lut(args) -> None:
     serve_fn = lg_ops.make_network_fn(source, block_b=args.microbatch,
                                       mesh=mesh)
     print(f"  {serve_fn.execution_plan.describe()} "
-          f"lookup_row_chunks={serve_fn.lookup_row_chunks}")
+          f"lookup_row_chunks={serve_fn.lookup_row_chunks} "
+          f"routing_macs_per_row={serve_fn.routing_macs_per_row}")
     drive_lut_serving(
         serve_fn, spec, data, requests=args.requests,
         microbatch=args.microbatch, deadline_ms=args.deadline_ms,

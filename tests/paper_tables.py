@@ -1,6 +1,7 @@
 """Random LUT tables at the paper rows' shapes, shared by the
-compile-only checks (``test_tpu_compile.py``) and the paper-width
-exactness test (``test_lut_fused.py``)."""
+compile-only checks (``test_tpu_compile.py``), the paper-width
+exactness test (``test_lut_fused.py``) and the wide-input tests
+(``test_wide_input.py``)."""
 import numpy as np
 
 import jax.numpy as jnp
@@ -8,6 +9,11 @@ import jax.numpy as jnp
 from repro.core import lut_synth as LS
 from repro.kernels.lut_gather.lut_gather import (MATMUL_ROUTE_MAX_BITS,
                                                  routing_matrix)
+
+
+# the benchmark's over-budget network: plans to 5 int4 fused segments
+DEEPER_WIDER_3X = dict(in_features=16, widths=(512,) * 6 + (5,), bits=2,
+                       fan_in=6, adder_width=2)
 
 
 def shaped_tables(spec, seed: int = 0):

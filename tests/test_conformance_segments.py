@@ -63,17 +63,27 @@ def _plan_property(tables, n_in, block_b, budget):
     """The plan_segments safety property: every emitted segment passes
     the estimator it was planned under (estimate == independent actual
     == the plan's recorded ledger, all <= budget), the bounds partition
-    the layer list, and per-layer mode is only ever chosen when some
-    single layer genuinely cannot fit."""
+    the layer list, the batch tile shrinks below ``block_b`` only when
+    some single layer busts the budget at twice the chosen tile, and
+    per-layer mode is only ever chosen when some single layer cannot
+    fit even at a 128-row tile."""
     plan = lg_ops.plan_segments(tables, block_b=block_b, n_in0=n_in,
                                 budget=budget, prefer_int4=False)
     widths = [t.conn.shape[0] for t in tables]
+
+    def most_needed(bb):
+        return max(lg_ops.fused_vmem_bytes(
+            tables[i:i + 1], bb, n_in if i == 0 else widths[i - 1])
+            for i in range(len(tables)))
+
     if plan.mode == "per_layer":
-        needs = [lg_ops.fused_vmem_bytes(
-            tables[i:i + 1], block_b, n_in if i == 0 else widths[i - 1])
-            for i in range(len(tables))]
-        assert max(needs) > budget, (needs, budget)
+        assert most_needed(128) > budget, (most_needed(128), budget)
         return plan
+    tile = plan.block_b[0]
+    assert set(plan.block_b) == {tile}
+    if tile != block_b:
+        assert tile % 128 == 0 and tile < block_b, (tile, block_b)
+        assert most_needed(2 * tile) > budget, (tile, budget)
     assert plan.bounds[0][0] == 0 and plan.bounds[-1][1] == len(tables)
     for (a, b), (c, d) in zip(plan.bounds, plan.bounds[1:]):
         assert b == c and a < b

@@ -28,12 +28,9 @@ from repro.configs import paper_models as PM
 from repro.core import lut_synth as LS
 from repro.kernels.lut_gather import ops
 
-from paper_tables import shaped_tables
+from paper_tables import DEEPER_WIDER_3X, shaped_tables
 
 B = 1024
-# the benchmark's over-budget network: plans to 5 int4 fused segments
-DEEPER_WIDER_3X = dict(in_features=16, widths=(512,) * 6 + (5,), bits=2,
-                       fan_in=6, adder_width=2)
 
 
 def _abstract(tables, sharding):
@@ -105,6 +102,21 @@ def test_fused_hdr_add2(one_chip, hdr_tables, int4):
                                            force_interpret=False),
         tables, 784, one_chip, one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_fused_cifar_hdr_add2(one_chip):
+    """The 3,072-wide first layer busts the budget at block_b 1024; the
+    planner's smaller tile compiles as one fused kernel, with the int4
+    slabs the artifact loads."""
+    tables = shaped_tables(PM.cifar_hdr_add2())
+    plan = ops.plan_segments(tables, n_in0=3072)
+    assert plan.mode == "fused" and plan.block_b[0] <= 256, \
+        plan.describe()
+    text = _compile_text(
+        lambda t, c: ops.lut_network_segmented(t, c, plan,
+                                               force_interpret=False),
+        LS.pack_tables_int4(tables), 3072, one_chip, one_chip, batch=4096)
+    assert text.count("tpu_custom_call") == 1
 
 
 def test_pipelined_multi_tile(one_chip, jsc_tables):
